@@ -58,7 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .arena import SlabArena
+from .arena import SlabArena, row_capacity
 from .buffers import Buffer, BufferView
 from .executors import ExecStats, SerialExecutor, group_by_signature
 from .scheduler import PLAN_MODES, SchedulerReport
@@ -853,8 +853,9 @@ def _loop_pallas_parts(program: EpochProgram, registry: DeviceOpRegistry,
                        arena: SlabArena):
     """Fast-path eligibility: ``(class_id, branches)`` when every spec fits
     the Pallas ready-queue kernel, else None. Requirements: one shape
-    class with padding-free 2-D rows, no views, arity <= 3, exactly one
-    output, and every fn IS its opcode's registered switch branch."""
+    class of 4-byte, padding-free 1-D rows (a ``[rows, width]`` slab), no
+    views, arity <= 3, exactly one output, and every fn IS its opcode's
+    registered switch branch."""
     if not program.specs:
         return None
     cids = {sp.class_id for st in program.specs
@@ -863,7 +864,7 @@ def _loop_pallas_parts(program: EpochProgram, registry: DeviceOpRegistry,
         return None
     cid = cids.pop()
     padded = arena.classes[cid].padded_shape
-    if len(padded) != 1:
+    if len(padded) != 1 or np.dtype(arena.classes[cid].dtype).itemsize != 4:
         return None
     branches = []
     for spec, fn, name in zip(program.specs, program.fns, program.opnames):
@@ -878,6 +879,39 @@ def _loop_pallas_parts(program: EpochProgram, registry: DeviceOpRegistry,
         branches.append(lambda x, y, z, _fn=fn, _k=arity:
                         _fn(*((x, y, z)[:_k])))
     return cid, tuple(branches)
+
+
+def _select_loop_pallas(loop_pallas: Optional[bool], program: EpochProgram,
+                        registry: DeviceOpRegistry, arena: SlabArena):
+    """The "loop" executor choice for one epoch: ``(parts, over_cap)``.
+
+    ``loop_pallas``: None = the Pallas kernel on TPU when the epoch is
+    eligible (interpreter elsewhere), True = force it (interpret mode off
+    TPU; still requires eligibility), False = the ``lax.while_loop``
+    interpreter always. An eligible epoch too large for the kernel's
+    SMEM/VMEM cap (``kernels/ready_queue.over_cap``) takes the
+    interpreter and reports ``over_cap=True`` so callers can count it."""
+    if loop_pallas is False or (loop_pallas is None
+                                and jax.default_backend() != "tpu"):
+        return None, False
+    parts = _loop_pallas_parts(program, registry, arena)
+    if parts is None:
+        return None, False
+    if _pallas_over_cap(program.n_tasks, program.dep_tbl.shape[1],
+                        arena, parts[0]):
+        return None, True
+    return parts, False
+
+
+def _pallas_over_cap(n_tasks: int, dep_width: int, arena: SlabArena,
+                     class_id: int) -> bool:
+    """Whether the class's slab (at the row capacity the next pack gives
+    it) plus the epoch's tables exceed the Pallas kernel's cap."""
+    from ..kernels.ready_queue import over_cap
+
+    rows = row_capacity(len(arena.rows(class_id)))
+    (width,) = arena.classes[class_id].padded_shape
+    return over_cap(n_tasks, dep_width, rows, width) is not None
 
 
 def _loop_task_table(program: EpochProgram) -> np.ndarray:
@@ -1058,12 +1092,8 @@ class DeviceWindowRunner:
                 arena.add(b)
         arena.add_tasks(tasks)
         program = lower_epoch_program(tasks, self.registry, arena)
-        parts = None
-        if self.loop_pallas is None:
-            if jax.default_backend() == "tpu":
-                parts = _loop_pallas_parts(program, self.registry, arena)
-        elif self.loop_pallas:
-            parts = _loop_pallas_parts(program, self.registry, arena)
+        parts, over_cap = _select_loop_pallas(self.loop_pallas, program,
+                                              self.registry, arena)
         plan_time = time.perf_counter() - t0
 
         stats = ExecStats()
@@ -1114,6 +1144,7 @@ class DeviceWindowRunner:
         report.plan_active_fraction = 1.0  # type: ignore[attr-defined]
         report.loop_executor = (  # type: ignore[attr-defined]
             "pallas" if parts is not None else "interpreter")
+        report.loop_pallas_over_cap = over_cap  # type: ignore[attr-defined]
         report.arena_stats = {  # type: ignore[attr-defined]
             "n_classes": arena.n_classes(),
             "total_waste_frac": round(arena.total_waste_frac(), 4),
@@ -1216,17 +1247,6 @@ def _device_lowerable(task: Task) -> bool:
         if tuple(shape) != tuple(base.shape):
             return False
     return True
-
-
-def _array_ready(arr: Any) -> bool:
-    """Non-blocking completion probe for an async-dispatched jax array
-    (True = the producing computation landed). Arrays without the probe
-    (older jax, plain numpy from a host fallback) count as ready — the
-    blocking retire path still guarantees correctness."""
-    try:
-        return bool(arr.is_ready())
-    except AttributeError:
-        return True
 
 
 class DeviceSession(SchedulerSession):
@@ -1354,9 +1374,15 @@ class DeviceSession(SchedulerSession):
         # bookkeeping lands in the one report without duplication.
         self._host_exec = SerialExecutor()
         self._host_exec.stats = self.stats
+        # id(Buffer) -> this session's device copy of a read-only buffer
+        # (see replicate()).
+        self._replicas: Dict[int, Any] = {}
         self.epochs = 0
         self.device_dispatches = 0
         self.loop_dispatches = 0  # ready-queue dispatches (subset of device)
+        # Pallas-eligible loop epochs over the kernel's SMEM/VMEM cap,
+        # which ran on the lax.while_loop interpreter instead.
+        self.loop_pallas_over_cap = 0
         self.host_task_dispatches = 0
         self.plan_cache_hits = 0
         self.plan_cache_misses = 0
@@ -1563,6 +1589,7 @@ class DeviceSession(SchedulerSession):
             self._device_dirty.pop(id(buf), None)
             self._host_dirty.pop(id(buf), None)
             self._host_dirty_tags.pop(id(buf), None)
+            self._replicas.pop(id(buf), None)
             return self.arena.free(buf)
 
     def _maybe_compact(self) -> None:
@@ -1705,7 +1732,7 @@ class DeviceSession(SchedulerSession):
         if need:
             self._sync_to_host(need.values(), tags=self._tags_of(tasks))
         for task in tasks:
-            self._host_exec.execute_wave([task])
+            self._host_exec.run_task(task, self._host_inputs(task))
             self.host_task_dispatches += 1
             for op in task.outputs:
                 b = operand_base(op)
@@ -1713,6 +1740,31 @@ class DeviceSession(SchedulerSession):
                 self._device_dirty.pop(id(b), None)
             self.waves.append([task.tid])
             self._note_retired(task)
+
+    def _host_inputs(self, task: Task) -> Tuple[Any, ...]:
+        """A host-path task's input values, committed to the pinned device
+        so that its dispatch runs there and its outputs stay there:
+        replicated buffers resolve to this session's own copy, every other
+        value is moved (a no-op for values already on the device). With
+        no pinned device the values pass through and the task runs where
+        they live."""
+        values = task.input_values()
+        if self.device is None:
+            return values
+        return tuple(
+            self._replicas[id(op)] if id(op) in self._replicas
+            else jax.device_put(v, self.device)
+            for op, v in zip(task.inputs, values))
+
+    def replicate(self, buf: Buffer) -> None:
+        """Keep a copy of read-only ``buf``'s value on the pinned device:
+        host-path tasks read that copy instead of moving the value per
+        dispatch. The caller guarantees no task writes ``buf`` while this
+        session is open (serving weights)."""
+        with self._lock:
+            if self.device is not None:
+                self._replicas[id(buf)] = jax.device_put(buf.value,
+                                                         self.device)
 
     def _drain_epoch_ordered(self) -> List[Task]:
         """Drain the live window into program order (the ready-queue
@@ -1737,15 +1789,18 @@ class DeviceSession(SchedulerSession):
             del self._plan_cache[key]
             self.plan_cache_invalidations += 1
             cached = None
+        if cached is not None and cached[4] is not None and \
+                _pallas_over_cap(len(tasks), cached[1]["dep_tbl"].shape[1],
+                                 self.arena, cached[4]):
+            # The slab grew past the Pallas kernel's cap since this plan
+            # was lowered: re-lower, which selects the interpreter.
+            del self._plan_cache[key]
+            cached = None
         if cached is None:
             program = lower_epoch_program(tasks, self.registry, self.arena)
-            parts = None
-            if self.loop_pallas is None:
-                if jax.default_backend() == "tpu":
-                    parts = _loop_pallas_parts(program, self.registry,
-                                               self.arena)
-            elif self.loop_pallas:
-                parts = _loop_pallas_parts(program, self.registry, self.arena)
+            parts, over_cap = _select_loop_pallas(
+                self.loop_pallas, program, self.registry, self.arena)
+            self.loop_pallas_over_cap += int(over_cap)
             # Interpreter payloads are bucket-padded only when the session
             # opted in (shape quantization — see _padded_loop_payload);
             # the Pallas fori_loop pops exactly n tasks, so the fast path
@@ -1774,7 +1829,8 @@ class DeviceSession(SchedulerSession):
                 for sp in st.inputs + st.outputs})
             gens = tuple(
                 (cid, self.arena.class_generation(cid)) for cid in class_ids)
-            cached = (prog, payload, len(program.specs), gens)
+            cached = (prog, payload, len(program.specs), gens,
+                      parts[0] if parts is not None else None)
             self._plan_cache[key] = cached
             self.plan_cache_misses += 1
             if self.plan_cache_limit is not None and \
@@ -1784,7 +1840,7 @@ class DeviceSession(SchedulerSession):
         else:
             self._plan_cache[key] = self._plan_cache.pop(key)
             self.plan_cache_hits += 1
-        run_fn, payload, _, _ = cached
+        run_fn, payload = cached[:2]
 
         self._refresh_slabs(tasks)
         out, _done = run_fn(tuple(self._slabs), payload)
@@ -1889,7 +1945,7 @@ class DeviceSession(SchedulerSession):
         retired = 0
         while self._inflight:
             dev_plan, probes = self._inflight[0]
-            if not block and not all(_array_ready(p) for p in probes):
+            if not block and not all(p.is_ready() for p in probes):
                 break
             if block:
                 jax.block_until_ready(list(probes))
@@ -1980,6 +2036,7 @@ class DeviceSession(SchedulerSession):
                 "epochs": self.epochs,
                 "device_dispatches": self.device_dispatches,
                 "loop_dispatches": self.loop_dispatches,
+                "loop_pallas_over_cap": self.loop_pallas_over_cap,
                 "host_task_dispatches": self.host_task_dispatches,
                 "plan_cache_hits": self.plan_cache_hits,
                 "plan_cache_misses": self.plan_cache_misses,

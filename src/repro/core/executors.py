@@ -83,18 +83,23 @@ class SerialExecutor:
         self._jit_cache: Dict[Tuple, Callable] = {}
 
     def execute_wave(self, tasks: Sequence[Task]) -> None:
-        t0 = time.perf_counter()
         for task in tasks:
-            fn = self._jit_cache.get(task.signature)
-            if fn is None:
-                fn = jax.jit(task.fn)
-                self._jit_cache[task.signature] = fn
-                self.stats.compiles += 1
-            out = fn(*task.input_values())
-            task.write_outputs(out)
-            self.stats.dispatches += 1
-            self.stats.tasks_run += 1
-            self.stats.wave_widths.append(1)
+            self.run_task(task, task.input_values())
+
+    def run_task(self, task: Task, values: Sequence[Any]) -> None:
+        """Dispatch one task on the given input values (its buffers'
+        values, or copies of them committed to the device it must run on)
+        and write its outputs."""
+        t0 = time.perf_counter()
+        fn = self._jit_cache.get(task.signature)
+        if fn is None:
+            fn = jax.jit(task.fn)
+            self._jit_cache[task.signature] = fn
+            self.stats.compiles += 1
+        task.write_outputs(fn(*values))
+        self.stats.dispatches += 1
+        self.stats.tasks_run += 1
+        self.stats.wave_widths.append(1)
         self.stats.exec_seconds += time.perf_counter() - t0
 
     def finalize(self) -> None:

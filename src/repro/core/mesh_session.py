@@ -137,37 +137,47 @@ class ShardLink:
         self.shards = list(shards)
         self.table = table
         self.requested_mode = mode
-        self.selected_mode = (mode if mode != "auto"
-                              else ("d2d" if self._probe_p2p() else "staged"))
+        # Why the link runs in its mode: the probe's finding under "auto",
+        # or that the caller forced it.
+        self.mode_reason = f"forced by transfer_mode={mode!r}"
+        if mode == "auto":
+            ok, self.mode_reason = self._probe_p2p()
+            mode = "d2d" if ok else "staged"
+        self.selected_mode = mode
         self.d2d_moves = 0
         self.staged_moves = 0
         self.d2d_fallbacks = 0
 
-    def _probe_p2p(self) -> bool:
+    def _probe_p2p(self) -> Tuple[bool, str]:
         """One-shot backend capability probe: can a committed array move
-        between two distinct shard devices with ``jax.device_put``? A
-        single-device mesh trivially supports the d2d path (the peer copy
-        degenerates to a same-device put)."""
+        between two distinct shard devices with ``jax.device_put``?
+        Returns ``(ok, reason)``. A single-device mesh trivially supports
+        the d2d path (the peer copy degenerates to a same-device put). A
+        backend without peer copies refuses the transfer with a runtime
+        error, which selects ``staged``; any other exception propagates."""
+        import jax
+        import jax.numpy as jnp
+
         devs: List[Any] = []
         for sh in self.shards:
             d = sh.device
             if d is not None and all(d is not e for e in devs):
                 devs.append(d)
         if not devs:
-            return False  # no pinned devices: nothing to commit a row onto
+            return False, "no pinned shard devices to commit a row onto"
         if len(devs) == 1:
-            return True
+            return True, "one shard device: peer copies are local puts"
         try:
-            import jax
-            import jax.numpy as jnp
-
             probe = jax.device_put(jnp.zeros((8,), jnp.float32), devs[0])
             peer = jax.device_put(probe, devs[1])
             jax.block_until_ready(peer)
-            (landed,) = peer.devices()
-            return landed == devs[1]
-        except Exception:
-            return False
+        except jax.errors.JaxRuntimeError as e:
+            return False, f"peer copy {devs[0]} -> {devs[1]} refused: {e}"
+        (landed,) = peer.devices()
+        if landed != devs[1]:
+            return False, (f"peer copy {devs[0]} -> {devs[1]} landed on "
+                           f"{landed}")
+        return True, f"peer copy {devs[0]} -> {devs[1]} landed"
 
     def move(self, base: Buffer, owner: int, dest: int) -> str:
         """Move ``base``'s row from shard ``owner`` to shard ``dest``;
@@ -192,6 +202,7 @@ class ShardLink:
         return {
             "transfer_mode": self.selected_mode,
             "transfer_mode_requested": self.requested_mode,
+            "transfer_mode_reason": self.mode_reason,
             "d2d_moves": self.d2d_moves,
             "staged_moves": self.staged_moves,
             "d2d_fallbacks": self.d2d_fallbacks,
@@ -267,6 +278,9 @@ class MeshDeviceSession(SchedulerSession):
         # ownership can't see them — the read home is what keeps a
         # tenant's requests landing where its weights already reside.
         self._read_home: Dict[int, int] = {}
+        # ids of read-only buffers every shard holds its own copy of (see
+        # replicate()): no shard owns them, so they never steer placement.
+        self._replicated: Set[int] = set()
         # Running per-shard placement totals (the least-loaded signal),
         # plus per-shard totals broken down by priority bucket: the
         # balance branch prefers the shard with the least equal-or-more-
@@ -304,6 +318,12 @@ class MeshDeviceSession(SchedulerSession):
         pos: Dict[int, int] = {}
         shard_of: Dict[int, int] = {}
         for i, t in enumerate(order):
+            for op in t.outputs:
+                if id(operand_base(op)) in self._replicated:
+                    raise ValueError(
+                        f"task {t.opcode}#{t.tid} writes replicated buffer "
+                        f"{operand_base(op).name!r}; replicated buffers are "
+                        "read-only")
             raw = sb.probe_writers(t.read_segments)
             haz = sb.insert(t.tid, t.read_segments, t.write_segments)
             pos[t.tid] = i
@@ -315,7 +335,8 @@ class MeshDeviceSession(SchedulerSession):
                 shard, reason = shard_of[latest], "hazard_upstream"
             else:
                 bids = [id(operand_base(op)) for op in
-                        tuple(t.inputs) + tuple(t.outputs)]
+                        tuple(t.inputs) + tuple(t.outputs)
+                        if id(operand_base(op)) not in self._replicated]
                 owners = [self._owner[b] for b in bids if b in self._owner]
                 homes = [self._read_home[b] for b in bids
                          if b in self._read_home]
@@ -346,7 +367,9 @@ class MeshDeviceSession(SchedulerSession):
                     reason = "balance"
             shard_of[t.tid] = shard
             for op in t.inputs:
-                self._read_home.setdefault(id(operand_base(op)), shard)
+                bid = id(operand_base(op))
+                if bid not in self._replicated:
+                    self._read_home.setdefault(bid, shard)
             self._placed[shard] += 1
             by_bucket = self._placed_by_bucket[shard]
             by_bucket[t.priority] = by_bucket.get(t.priority, 0) + 1
@@ -534,6 +557,17 @@ class MeshDeviceSession(SchedulerSession):
         with self._lock:
             return self._owner.get(id(buf))
 
+    def replicate(self, buf: Buffer) -> None:
+        """Give every shard its own copy of read-only ``buf`` on its device
+        (one copy per device: the put is a no-op where the value already
+        lives). Host-path tasks on a shard then read the local copy — the
+        serving weights, one replica per chip — and the buffer neither
+        steers placement nor moves over the link. Writing it raises."""
+        with self._lock:
+            self._replicated.add(id(buf))
+            for sh in self._shards:
+                sh.replicate(buf)
+
     # -- row lifecycle -----------------------------------------------------
     def release_buffer(self, buf: Buffer) -> bool:
         """Forward a producer's release to every shard (each holds its own
@@ -546,6 +580,7 @@ class MeshDeviceSession(SchedulerSession):
             self._owner.pop(id(buf), None)
             self._copies.pop(id(buf), None)
             self._read_home.pop(id(buf), None)
+            self._replicated.discard(id(buf))
             return freed
 
     # -- lifecycle ---------------------------------------------------------

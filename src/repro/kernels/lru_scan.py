@@ -2,13 +2,15 @@
 
 Serves RG-LRU (recurrentgemma) and the Mamba SSM's per-channel recurrence
 (falcon-mamba). The sequence axis is the innermost grid dimension so the
-carry ``h`` lives in VMEM scratch across chunks; within a chunk the
-recurrence is an in-register fori_loop over time steps — HBM traffic is
-exactly one read of (a, b) and one write of h per element, the memory-
-bound optimum for a recurrence (arithmetic intensity ~2 flops/6 bytes).
+carry ``h`` lives in VMEM scratch across chunks; within a chunk a
+fori_loop walks aligned 8-row tiles and the recurrence steps through each
+tile's rows in registers — HBM traffic is exactly one read of (a, b) and
+one write of h per element, the memory-bound optimum for a recurrence
+(arithmetic intensity ~2 flops/6 bytes).
 
 Shapes: a, b: [B, S, D]; h0: [B, D] -> out h: [B, S, D].
-Block: (1, chunk, D) — D is lane-aligned (multiple of 128 for real TPU).
+Blocks: (1, chunk, D) with chunk a multiple of 8, and h0 as (1, 1, D) —
+every block's last two dims are tile-aligned or whole.
 """
 
 from __future__ import annotations
@@ -23,25 +25,33 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["lru_scan"]
 
+_SUBLANES = 8
+
 
 def _lru_kernel(h0_ref, a_ref, b_ref, o_ref, h_scr, *, chunk):
     si = pl.program_id(1)
 
     @pl.when(si == 0)
     def _init():
-        h_scr[...] = h0_ref[0].astype(jnp.float32)[None]
+        h_scr[...] = h0_ref[0].astype(jnp.float32)
 
-    a = a_ref[0].astype(jnp.float32)  # [chunk, D]
-    b = b_ref[0].astype(jnp.float32)
+    d = h_scr.shape[-1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (_SUBLANES, d), 0)
 
-    def step(t, carry):
-        h = carry
-        h = a[t] * h + b[t]
-        o_ref[0, t, :] = h.astype(o_ref.dtype)
+    def block(k, h):
+        # One 8-row tile per iteration: aligned loads and one aligned
+        # store; the recurrence itself steps through the tile's rows.
+        start = pl.multiple_of(k * _SUBLANES, _SUBLANES)
+        a = a_ref[0, pl.ds(start, _SUBLANES), :].astype(jnp.float32)
+        b = b_ref[0, pl.ds(start, _SUBLANES), :].astype(jnp.float32)
+        out = jnp.zeros((_SUBLANES, d), jnp.float32)
+        for t in range(_SUBLANES):
+            h = a[t:t + 1] * h + b[t:t + 1]
+            out = jnp.where(row == t, jnp.broadcast_to(h, out.shape), out)
+        o_ref[0, pl.ds(start, _SUBLANES), :] = out.astype(o_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, chunk, step, h_scr[0, :])
-    h_scr[...] = h[None]
+    h_scr[...] = jax.lax.fori_loop(0, chunk // _SUBLANES, block, h_scr[...])
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -56,7 +66,8 @@ def lru_scan(
     bsz, s, d = a.shape
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    chunk = min(chunk, s)
+    # Whole 8-row tiles: the time axis is the sublane axis of each block.
+    chunk = -(-min(chunk, s) // _SUBLANES) * _SUBLANES
     s_pad = -(-s // chunk) * chunk
     if s_pad != s:
         # pad with a=1, b=0 (identity recurrence) so the carry is unaffected
@@ -67,7 +78,7 @@ def lru_scan(
         functools.partial(_lru_kernel, chunk=chunk),
         grid=(bsz, s_pad // chunk),
         in_specs=[
-            pl.BlockSpec((1, d), lambda bi, si: (bi, 0)),
+            pl.BlockSpec((1, 1, d), lambda bi, si: (bi, 0, 0)),
             pl.BlockSpec((1, chunk, d), lambda bi, si: (bi, si, 0)),
             pl.BlockSpec((1, chunk, d), lambda bi, si: (bi, si, 0)),
         ],
@@ -75,5 +86,5 @@ def lru_scan(
         out_shape=jax.ShapeDtypeStruct((bsz, s_pad, d), b.dtype),
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
         interpret=interpret,
-    )(h0, a, b)
+    )(h0[:, None, :], a, b)
     return out[:, :s, :]

@@ -16,69 +16,127 @@ Pallas program:
   decrements its dependents' counters and pushes zero-crossings onto the
   ring — no host involvement anywhere in the loop.
 
-A grid-based dispatch (``wave_elementwise``-style prefetched index maps)
-cannot express this: index maps are fixed at launch, but the ring's
-contents *are* the schedule and only exist as the loop runs. So the whole
-epoch executes as a single program (``grid=(1,)``) whose ``fori_loop``
-pops exactly ``n`` tasks: program order is topological, so every edge
-points forward and the ring can never starve — the i-th iteration always
-has a task to pop (property-tested against the serial baseline).
+A grid-based dispatch (prefetched index maps) cannot express this: index
+maps are fixed at launch, but the ring's contents *are* the schedule and
+only exist as the loop runs. So the whole epoch executes as a single
+program (``grid=(1,)``) whose ``fori_loop`` pops exactly ``n`` tasks:
+program order is topological, so every edge points forward and the ring
+can never starve — the i-th iteration always has a task to pop
+(property-tested against the serial baseline).
 
-Eligibility is narrow by design — one shape class, padding-free 2-D rows,
-arity <= 3, one output, and every kernel fn registered in the device
-registry's **switch-branch table** (the fixed HW kernel set). Everything
-else runs through the structurally identical ``lax.while_loop``
-interpreter in ``core/device_dispatch.py``; both advance the frontier in
-one dispatch.
+Memory placement follows what Mosaic can index at run time:
+
+* every control scalar — task table, dependency table, ring, counters,
+  completion flags — lives in **SMEM** (the tables arrive as scalar
+  prefetch operands, flattened to 1-D; ring and counters are SMEM
+  scratch), so each pop and each dependent wake-up is a scalar load or
+  store at a dynamic address;
+* the slab lives in **VMEM** as ``[rows, 1, lanes]``: a task's row is a
+  dynamic index on the untiled leading axis, and the row width is padded
+  to whole 128-lane vregs.
+
+Both memories are small, so the kernel has a stated cap
+(:data:`MAX_TASKS`, :data:`MAX_DEPS`, :data:`MAX_SLAB_VMEM_BYTES`);
+:func:`over_cap` says whether an epoch fits. Callers send epochs over the
+cap to the ``lax.while_loop`` interpreter in ``core/device_dispatch.py``
+instead, and count them.
+
+Eligibility is narrow by design — one 4-byte shape class, padding-free
+1-D rows, arity <= 3, one output, and every kernel fn registered in the
+device registry's **switch-branch table** (the fixed HW kernel set).
+Everything else runs through the structurally identical
+``lax.while_loop`` interpreter; both advance the frontier in one dispatch.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional, Sequence, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["ready_queue_call"]
+__all__ = ["ready_queue_call", "over_cap", "slab_vmem_bytes", "MAX_TASKS",
+           "MAX_DEPS", "MAX_SLAB_VMEM_BYTES"]
+
+LANES = 128
+SUBLANES = 8
+#: Tasks per epoch. SMEM holds about ``n * (10 + m)`` int32 words.
+MAX_TASKS = 1024
+#: Dependency-table width (forward edges per task).
+MAX_DEPS = 16
+#: The slab's VMEM footprint (input and output blocks, each double
+#: buffered): ``4 * rows * 8 sublanes * lanes * 4 bytes`` must stay under
+#: the 16 MiB scoped-VMEM default of a v5e core.
+MAX_SLAB_VMEM_BYTES = 8 << 20
+
+
+def _lanes(width: int) -> int:
+    return -(-width // LANES) * LANES
+
+
+def slab_vmem_bytes(rows: int, width: int) -> int:
+    """VMEM the kernel's slab blocks take for a ``[rows, width]`` 4-byte
+    slab: each row pads to one ``(8, lanes)`` tile, and the input and
+    output blocks are each double buffered."""
+    return 4 * rows * SUBLANES * _lanes(width) * 4
+
+
+def over_cap(n_tasks: int, dep_width: int, rows: int,
+             width: int) -> Optional[str]:
+    """Why an epoch of ``n_tasks`` tasks with ``dep_width`` forward edges
+    each, over a ``[rows, width]`` slab, does not fit the kernel — or None
+    when it does."""
+    if n_tasks > MAX_TASKS:
+        return f"{n_tasks} tasks > MAX_TASKS={MAX_TASKS}"
+    if dep_width > MAX_DEPS:
+        return f"dependency width {dep_width} > MAX_DEPS={MAX_DEPS}"
+    vmem = slab_vmem_bytes(rows, width)
+    if vmem > MAX_SLAB_VMEM_BYTES:
+        return (f"slab [{rows}, {width}] needs {vmem} B of VMEM > "
+                f"MAX_SLAB_VMEM_BYTES={MAX_SLAB_VMEM_BYTES}")
+    return None
 
 
 def _ready_queue_kernel(task_ref, dep_ref, ring0_ref, rem0_ref, tail_ref,
-                        slab_in_ref, slab_ref, ring_ref, rem_ref, done_ref,
-                        *, branches):
-    # Copy inputs into the mutable outputs once; the loop then runs
-    # entirely over output refs (no input_output_aliases dependency).
+                        slab_in_ref, slab_ref, done_ref, ring_ref, rem_ref,
+                        *, n, m, branches):
     slab_ref[...] = slab_in_ref[...]
-    ring_ref[...] = ring0_ref[...]
-    rem_ref[...] = rem0_ref[...]
-    done_ref[...] = jnp.zeros_like(done_ref)
-    n, m = dep_ref.shape
-    one = jnp.ones((1,), done_ref.dtype)
+
+    def init(i, carry):
+        ring_ref[i] = ring0_ref[i]
+        rem_ref[i] = rem0_ref[i]
+        return carry
+
+    def clear(i, carry):
+        done_ref[i] = 0
+        return carry
+
+    jax.lax.fori_loop(0, n + 1, init, 0)
+    jax.lax.fori_loop(0, n, clear, 0)
 
     def body(i, tail):
         # head == i: one pop per iteration; edges point forward in program
         # order, so the ring holds at least i+1 entries by iteration i.
-        t = pl.load(ring_ref, (pl.dslice(i, 1),))[0]
-        task = pl.load(task_ref, (pl.dslice(t, 1), slice(None)))[0]
-        x = pl.load(slab_ref, (pl.dslice(task[1], 1), slice(None)))[0]
-        y = pl.load(slab_ref, (pl.dslice(task[2], 1), slice(None)))[0]
-        z = pl.load(slab_ref, (pl.dslice(task[3], 1), slice(None)))[0]
-        res = jax.lax.switch(task[0], branches, x, y, z)
-        pl.store(slab_ref, (pl.dslice(task[4], 1), slice(None)),
-                 res.astype(slab_ref.dtype)[None])
-        pl.store(done_ref, (pl.dslice(t, 1),), one)
-        deps = pl.load(dep_ref, (pl.dslice(t, 1), slice(None)))[0]
+        t = ring_ref[i]
+        row = t * 5
+        x = slab_ref[task_ref[row + 1]]
+        y = slab_ref[task_ref[row + 2]]
+        z = slab_ref[task_ref[row + 3]]
+        res = jax.lax.switch(task_ref[row], branches, x, y, z)
+        slab_ref[task_ref[row + 4]] = res.astype(slab_ref.dtype)
+        done_ref[t] = 1
         # Retire: decrement each dependent's counter; zero-crossings join
         # the ring at the tail. Sentinel edges (== n) hit the trash slot of
         # `remaining`/`ring` (both sized n+1), never a live counter.
         for j in range(m):
-            d = deps[j]
-            rem = pl.load(rem_ref, (pl.dslice(d, 1),))[0] - 1
-            pl.store(rem_ref, (pl.dslice(d, 1),), rem[None])
-            ready = (d < n) & (rem == 0)
-            slot = jnp.where(ready, tail, n)
-            pl.store(ring_ref, (pl.dslice(slot, 1),), d[None])
+            d = dep_ref[t * m + j]
+            rem = rem_ref[d] - 1
+            rem_ref[d] = rem
+            ready = jnp.logical_and(d < n, rem == 0)
+            ring_ref[jnp.where(ready, tail, n)] = d
             tail = tail + ready.astype(jnp.int32)
         return tail
 
@@ -100,20 +158,35 @@ def ready_queue_call(
 ) -> Tuple[jax.Array, jax.Array]:
     """Run one epoch's ready-queue program; returns ``(slab', done)``
     where ``done`` is the ``[n] int32`` per-task completion-flag array
-    (all ones iff the queue drained — the lowering guarantees it)."""
-    n = task_tbl.shape[0]
+    (all ones iff the queue drained — the lowering guarantees it). The
+    epoch must fit the kernel (:func:`over_cap` is None)."""
+    n, m = dep_tbl.shape
+    rows, width = slab.shape
+    reason = over_cap(n, m, rows, width)
+    if reason is not None:
+        raise ValueError(f"ready-queue epoch over the kernel's cap: {reason}")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    slab_out, _ring, _rem, done = pl.pallas_call(
-        functools.partial(_ready_queue_kernel, branches=branches),
+    lanes = _lanes(width)
+    slab3 = jnp.pad(slab, ((0, 0), (0, lanes - width)))[:, None, :]
+    whole = pl.BlockSpec((rows, 1, lanes), lambda i, *_: (0, 0, 0))
+    slab_out, done = pl.pallas_call(
+        functools.partial(_ready_queue_kernel, n=n, m=m, branches=branches),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(1,),
+            in_specs=[whole],
+            out_specs=[whole, pl.BlockSpec(memory_space=pltpu.SMEM)],
+            scratch_shapes=[pltpu.SMEM((n + 1,), jnp.int32),
+                            pltpu.SMEM((n + 1,), jnp.int32)],
+        ),
         out_shape=(
-            jax.ShapeDtypeStruct(slab.shape, slab.dtype),
-            jax.ShapeDtypeStruct((n + 1,), jnp.int32),
-            jax.ShapeDtypeStruct((n + 1,), jnp.int32),
+            jax.ShapeDtypeStruct(slab3.shape, slab.dtype),
             jax.ShapeDtypeStruct((n,), jnp.int32),
         ),
         interpret=interpret,
-    )(task_tbl.astype(jnp.int32), dep_tbl.astype(jnp.int32),
+    )(task_tbl.astype(jnp.int32).reshape(-1),
+      dep_tbl.astype(jnp.int32).reshape(-1),
       ring0.astype(jnp.int32), rem0.astype(jnp.int32),
-      tail0.astype(jnp.int32), slab)
-    return slab_out, done
+      tail0.astype(jnp.int32), slab3)
+    return slab_out[:, 0, :width], done

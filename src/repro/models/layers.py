@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
@@ -38,6 +39,12 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
     ).astype(x.dtype)
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
 def dense_init(key, shape, dtype, scale: float = 1.0):
+    """Scaled normal draw, made in float32 and cast to ``dtype`` inside one
+    compiled program: only the ``dtype`` result is allocated, never a
+    float32 temporary of the weight's size (a 24-layer stacked FFN weight
+    at d_model 3840 would otherwise hold a 3.8 GB float32 draw beside a
+    bfloat16 model)."""
     fan_in = shape[0] if len(shape) >= 2 else 1
     return (scale * jax.random.normal(key, shape, jnp.float32) / np.sqrt(fan_in)).astype(dtype)

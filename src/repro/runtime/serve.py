@@ -70,7 +70,7 @@ from ..models import decode_step, init_cache, prefill
 from ..models.config import ArchConfig
 
 __all__ = ["Request", "AdmissionQueueFull", "DrainTimeout",
-           "ContinuousBatchingServer", "SessionServer",
+           "ContinuousBatchingServer", "SessionServer", "INVALID_TOKEN",
            "PRIORITY_HIGH", "PRIORITY_NORMAL", "PRIORITY_LOW"]
 
 _rid = itertools.count()
@@ -139,6 +139,20 @@ class Request:
         return self.t_finish - self.t_arrival
 
 
+#: The token a serving kernel emits when any logit of the step is not
+#: finite — outside ``[0, vocab)``, so a broken model never passes for one
+#: that sampled a real token.
+INVALID_TOKEN = -1
+
+
+def _next_token(logits, vocab: int):
+    """Greedy token of the last position, or :data:`INVALID_TOKEN` for a
+    row with any non-finite logit."""
+    last = logits[:, -1, :vocab]
+    return jnp.where(jnp.all(jnp.isfinite(last), axis=-1),
+                     jnp.argmax(last, axis=-1), INVALID_TOKEN)
+
+
 class _ServingCore:
     """Slots, kernels, and QoS bounded admission — shared by both servers.
 
@@ -164,7 +178,6 @@ class _ServingCore:
                  aging_s: Optional[float] = 5.0):
         assert cfg.frontend is None, "serving driver uses token models"
         self.cfg = cfg
-        self.params = params
         self.max_len = max_len
         self.max_queue = max_queue
         self.history_limit = history_limit
@@ -190,6 +203,15 @@ class _ServingCore:
         # (asserted by benchmarks/bench_soak.py).
         self.report_log: Deque[Dict] = collections.deque(maxlen=history_limit)
 
+        # The weights reach every prefill and decode program as an
+        # operand, never through a closure: jit lowers a closed-over array
+        # into the program as a constant, which at published widths would
+        # copy the whole model into each compiled program. Every serving
+        # task reads this one buffer; read-after-read is no hazard, so it
+        # serializes nothing.
+        self.weights = self.pool.alloc((1,), np.float32, name="weights",
+                                       value=params)
+
         # one opaque buffer per slot: value = (cache pytree, last_token, pos)
         self.slots = []
         for i in range(max_slots):
@@ -201,22 +223,21 @@ class _ServingCore:
 
         cfg_ = cfg
 
-        def _prefill_fn(slot_val, tokens):
+        def _prefill_fn(slot_val, params, tokens):
             cache, _, _ = slot_val
-            logits, cache = prefill(self.params, cfg_, tokens, cache)
-            tok = jnp.argmax(logits[:, -1, : cfg_.vocab], axis=-1)
+            logits, cache = prefill(params, cfg_, tokens, cache)
             # list-of-one: each element maps to one output buffer
-            return [(cache, tok, jnp.asarray(tokens.shape[1], jnp.int32))]
+            return [(cache, _next_token(logits, cfg_.vocab),
+                     jnp.asarray(tokens.shape[1], jnp.int32))]
 
-        def _decode_fn(*slot_vals):
+        def _decode_fn(params, *slot_vals):
             outs = []
             for cache, tok, pos in slot_vals:
                 pos = jnp.asarray(pos, jnp.int32)
                 logits, cache = decode_step(
-                    self.params, cfg_, tok[:, None], cache, pos,
+                    params, cfg_, tok[:, None], cache, pos,
                 )
-                nxt = jnp.argmax(logits[:, -1, : cfg_.vocab], axis=-1)
-                outs.append((cache, nxt, pos + 1))
+                outs.append((cache, _next_token(logits, cfg_.vocab), pos + 1))
             return outs
 
         self._prefill_kernel = AcsKernel(name="req_prefill", fn=_prefill_fn)
@@ -435,7 +456,7 @@ class ContinuousBatchingServer(_ServingCore):
             tok_buf = self._grant_slot(req)
             prompt_bufs.append(tok_buf.name)
             self._prefill_kernel.launch(
-                stream, inputs=(self.slots[req.slot], tok_buf),
+                stream, inputs=(self.slots[req.slot], self.weights, tok_buf),
                 outputs=(self.slots[req.slot],),
             )
 
@@ -447,7 +468,8 @@ class ContinuousBatchingServer(_ServingCore):
                     and int(self.slots[s].value[2]) < self.max_len - 1]
         if decoding:
             bufs = tuple(self.slots[s] for s in decoding)
-            self._decode_kernel.launch(stream, inputs=bufs, outputs=bufs)
+            self._decode_kernel.launch(stream, inputs=(self.weights,) + bufs,
+                                       outputs=bufs)
 
         if not stream.tasks:
             return []
@@ -610,6 +632,9 @@ class SessionServer(_ServingCore):
                                              history_limit=history_limit,
                                              transfer_mode=transfer_mode,
                                              overlap_drains=overlap_drains)
+            # One weight replica per shard device; each shard's serving
+            # tasks run on its device against its own copy.
+            self.session.replicate(self.weights)
             # Same row-lifecycle wiring as "device", fanned out to every
             # shard's arena (a freed buffer may hold rows on several).
             self.pool.add_free_hook(self.session.release_buffer)
@@ -757,7 +782,8 @@ class SessionServer(_ServingCore):
             return
         stream = self._stream_for(req)
         task = self._prefill_kernel.launch(
-            stream, inputs=(self.slots[s], tok_buf), outputs=(self.slots[s],))
+            stream, inputs=(self.slots[s], self.weights, tok_buf),
+            outputs=(self.slots[s],))
         self.task_kinds[task.tid] = "prefill"
         # Decode rounds the cache can actually hold: zero when max_new=0 or
         # the prompt already fills it — never force a phantom round that
@@ -791,7 +817,8 @@ class SessionServer(_ServingCore):
                else min(req.rounds_left, self.preempt_rounds))
         bufs = (self.slots[s],)
         for k in range(seg):
-            dtask = self._decode_kernel.launch(stream, inputs=bufs, outputs=bufs)
+            dtask = self._decode_kernel.launch(
+                stream, inputs=(self.weights,) + bufs, outputs=bufs)
             self.task_kinds[dtask.tid] = "decode"
             self.session.on_task_retired(
                 dtask,

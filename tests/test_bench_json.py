@@ -87,6 +87,9 @@ def test_json_flag_requires_path(monkeypatch):
 
 def _bench_env():
     env = dict(os.environ)
+    # the runner's __main__ turns JAX's persistent compilation cache on;
+    # tests keep it off
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     env["PYTHONPATH"] = os.path.join(REPO_ROOT, "src") + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
     return env

@@ -226,3 +226,69 @@ class TestPlanModes:
         assert window.stats.inserted == 20
         assert window.stats.retired == 20
         assert sum(len(w) for w in waves) == 20
+
+
+class TestLoopPallasCap:
+    """Epochs eligible for the Pallas ready queue but over its SMEM/VMEM
+    cap run on the lax.while_loop interpreter, and say so."""
+
+    @staticmethod
+    def _chains(n_chains, depth):
+        from repro.kernels.ops import LOOP_BRANCHES
+
+        rng = np.random.RandomState(3)
+        pool = BufferPool()
+        states = [pool.alloc((D,), np.float32,
+                             value=jnp.asarray(rng.randn(D).astype(np.float32)))
+                  for _ in range(n_chains)]
+        weight = pool.alloc((D,), np.float32,
+                            value=jnp.asarray(rng.randn(D).astype(np.float32)))
+        tasks = []
+        for s in states:
+            for k in range(depth):
+                op = "axpy" if k % 2 == 0 else "mul"
+                r, w = default_segments((s, weight), (s,))
+                tasks.append(Task(opcode=op, fn=LOOP_BRANCHES[op],
+                                  inputs=(s, weight), outputs=(s,),
+                                  read_segments=r, write_segments=w))
+        return states, tasks
+
+    def _reference(self, n_chains, depth):
+        states, tasks = self._chains(n_chains, depth)
+        run_serial(tasks)
+        return np.stack([np.asarray(s.value) for s in states])
+
+    @pytest.mark.parametrize("depth,executor,over", [
+        (4, "pallas", False),
+        (None, "interpreter", True),   # MAX_TASKS + 2 tasks
+    ])
+    def test_runner_reports_executor_and_cap(self, depth, executor, over):
+        from repro.kernels.ops import register_loop_branches
+        from repro.kernels.ready_queue import MAX_TASKS
+
+        depth = depth or MAX_TASKS // 2 + 1
+        ref = self._reference(2, depth)
+        states, tasks = self._chains(2, depth)
+        runner = DeviceWindowRunner(plan_mode="loop", loop_pallas=True)
+        register_loop_branches(runner.registry)
+        report = runner.run(tasks)
+        assert report.loop_executor == executor
+        assert report.loop_pallas_over_cap is over
+        np.testing.assert_array_equal(
+            np.stack([np.asarray(s.value) for s in states]), ref)
+
+    def test_session_counts_over_cap_epochs(self):
+        from repro.core import DeviceSession
+        from repro.kernels.ops import register_loop_branches
+        from repro.kernels.ready_queue import MAX_TASKS
+
+        depth = MAX_TASKS // 2 + 1
+        ref = self._reference(2, depth)
+        states, tasks = self._chains(2, depth)
+        session = DeviceSession(plan_mode="loop", loop_pallas=True)
+        register_loop_branches(session.registry)
+        session.submit(tasks)
+        session.close()
+        assert session.session_stats()["loop_pallas_over_cap"] == 1
+        np.testing.assert_array_equal(
+            np.stack([np.asarray(s.value) for s in states]), ref)

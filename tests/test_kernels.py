@@ -174,3 +174,83 @@ class TestWaveElementwise:
         got = apply_wave(slab, desc, rows)
         expect = ref.wave_elementwise_ref(slab, ops, ins, outs, _BRANCHES)
         np.testing.assert_allclose(np.asarray(got), np.asarray(expect), rtol=1e-6)
+
+
+class TestReadyQueue:
+    """The Pallas ready-queue kernel (interpret mode here) against the
+    program-order baseline: random forward DAGs over a row slab, every
+    task one LOOP_BRANCHES kernel writing one row."""
+
+    @staticmethod
+    def _dag(seed, n, rows, width):
+        from repro.kernels.ops import LOOP_BRANCHES
+
+        rng = np.random.RandomState(seed)
+        fns = list(LOOP_BRANCHES.values())
+        slab = rng.randn(rows, width).astype(np.float32)
+        tasks = [(int(rng.randint(len(fns))), int(rng.randint(rows)),
+                  int(rng.randint(rows)), int(rng.randint(rows)))
+                 for _ in range(n)]
+        # forward edges as a scoreboard finds them: RAW and WAW from each
+        # row's last writer, WAR from the row's readers since that write
+        deps = [set() for _ in range(n)]
+        writer, readers = {}, {}
+        for j, (_, a, b, o) in enumerate(tasks):
+            ups = {writer[r] for r in (a, b, o) if r in writer}
+            ups |= readers.get(o, set())
+            for i in ups - {j}:
+                deps[i].add(j)
+            for r in (a, b):
+                readers.setdefault(r, set()).add(j)
+            writer[o], readers[o] = j, set()
+        indeg = np.zeros(n + 1, np.int32)
+        for ds in deps:
+            for j in ds:
+                indeg[j] += 1
+        m = max(1, max(len(ds) for ds in deps))
+        dep_tbl = np.full((n, m), n, np.int32)
+        for i, ds in enumerate(deps):
+            dep_tbl[i, :len(ds)] = sorted(ds)
+        ready = np.flatnonzero(indeg[:n] == 0)
+        ring0 = np.full(n + 1, n, np.int32)
+        ring0[:len(ready)] = ready
+        task_tbl = np.asarray([(f, a, b, a, o) for f, a, b, o in tasks],
+                              np.int32)
+        ref = jnp.asarray(slab)
+        for f, a, b, o in tasks:
+            ref = ref.at[o].set(jax.jit(fns[f])(ref[a], ref[b]))
+        branches = tuple((lambda x, y, z, _f=f: _f(x, y)) for f in fns)
+        args = (jnp.asarray(slab), jnp.asarray(task_tbl),
+                jnp.asarray(dep_tbl), jnp.asarray(ring0),
+                jnp.asarray(indeg), jnp.asarray([len(ready)], np.int32))
+        return args, branches, np.asarray(ref)
+
+    @pytest.mark.parametrize("seed,n,rows,width", [
+        (0, 12, 6, 16),     # narrow rows: lanes pad 16 -> 128
+        (1, 40, 10, 130),   # width just past one vreg: pads to 256
+        (2, 64, 4, 128),    # dense hazards over few rows
+    ])
+    def test_matches_program_order(self, seed, n, rows, width):
+        from repro.kernels.ready_queue import ready_queue_call
+
+        args, branches, ref = self._dag(seed, n, rows, width)
+        slab, done = ready_queue_call(*args, branches=branches,
+                                      interpret=True)
+        assert np.asarray(done).tolist() == [1] * n
+        np.testing.assert_array_equal(np.asarray(slab), ref)
+
+    def test_over_cap_is_refused(self):
+        from repro.kernels.ready_queue import (MAX_DEPS, MAX_SLAB_VMEM_BYTES,
+                                               MAX_TASKS, over_cap,
+                                               ready_queue_call,
+                                               slab_vmem_bytes)
+
+        assert over_cap(MAX_TASKS, MAX_DEPS, 8, 128) is None
+        assert "MAX_TASKS" in over_cap(MAX_TASKS + 1, 1, 8, 128)
+        assert "MAX_DEPS" in over_cap(8, MAX_DEPS + 1, 8, 128)
+        rows = MAX_SLAB_VMEM_BYTES // slab_vmem_bytes(1, 128) + 1
+        assert "VMEM" in over_cap(8, 1, rows, 128)
+        args, branches, _ = self._dag(0, 12, 6, 16)
+        big = (jnp.zeros((rows, 128), jnp.float32),) + args[1:]
+        with pytest.raises(ValueError, match="cap"):
+            ready_queue_call(*big, branches=branches, interpret=True)

@@ -144,6 +144,61 @@ class TestShardLinkAudit:
         with pytest.raises(ValueError, match="transfer_mode"):
             ShardLink([], None, mode="bogus")
 
+    @pytest.mark.parametrize("mode,reason", [
+        ("auto", "one shard device"),   # logical shards share one device
+        ("staged", "forced by transfer_mode='staged'"),
+    ])
+    def test_mode_reason_in_stats(self, mode, reason):
+        session = MeshDeviceSession(window_size=16, n_shards=2,
+                                    transfer_mode=mode)
+        stats = session.session_stats()
+        session.close()
+        assert reason in stats["transfer_mode_reason"]
+
+
+class TestReplicatedBuffers:
+    """A replicated read-only buffer (serving weights): every shard reads
+    its own copy, it never steers placement, and writing it raises."""
+
+    def _universe(self):
+        rng = np.random.RandomState(5)
+        pool = BufferPool()
+        shared = pool.alloc((D,), np.float32, name="shared",
+                            value=jnp.asarray(rng.randn(D).astype(np.float32)))
+        states = [pool.alloc((D,), np.float32, name=f"s{i}",
+                             value=jnp.asarray(rng.randn(D).astype(np.float32)))
+                  for i in range(N_SHARDS)]
+        axpy, _ = _kernels()
+        stream = TaskStream()
+        tasks = [axpy.launch(stream, inputs=(s, shared), outputs=(s,))
+                 for s in states]
+        return shared, states, tasks
+
+    def test_replicated_input_does_not_pull_placement(self):
+        shared, states, tasks = self._universe()
+        session = MeshDeviceSession(window_size=16, n_shards=N_SHARDS)
+        session.replicate(shared)
+        session.submit(tasks)
+        session.close()
+        # independent chains whose only common operand is replicated
+        # spread by balance instead of collecting on its read home
+        placed = session.session_stats()["placements"]
+        assert placed["balance"] == N_SHARDS
+        assert placed["read_affinity"] == 0
+        shared2, states2, tasks2 = self._universe()
+        run_serial(tasks2)
+        np.testing.assert_array_equal(_snap(states), _snap(states2))
+
+    def test_write_to_replicated_buffer_raises(self):
+        shared, states, _ = self._universe()
+        axpy, _ = _kernels()
+        session = MeshDeviceSession(window_size=16, n_shards=N_SHARDS)
+        session.replicate(shared)
+        axpy.launch(TaskStream(sink=session), inputs=(states[0], shared),
+                    outputs=(shared,))
+        with pytest.raises(ValueError, match="replicated"):
+            session.flush()
+
 
 class TestLateObserverSync:
     """Satellite: a late observer of a retired task must sync only the

@@ -4,6 +4,7 @@ co-scheduling (prefill alongside in-flight decode), apply multi-tenant
 fairness, and exert backpressure through the bounded admission FIFO."""
 
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -310,3 +311,123 @@ class TestLifetimeRegressions:
         assert rows_after[-1] == rows_after[0]  # flat, not 3 rows/wave
         assert server.session.arena.recycled_rows > 0
         server.close()
+
+
+# ---------------------------------------------------------------------------
+# Program shape: the weights are an operand of every serving program, never
+# a closure constant (at published widths a constant would copy the whole
+# model into each compiled prefill and decode program).
+# ---------------------------------------------------------------------------
+
+_CONST_TYPE = re.compile(r"stablehlo\.constant .*: tensor<([^>]*)>")
+_DTYPE_BYTES = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2, "i64": 8, "i32": 4,
+                "ui32": 4, "i16": 2, "i8": 1, "ui8": 1, "i1": 1}
+
+
+def _constant_bytes(hlo_text):
+    total = 0
+    for spec in _CONST_TYPE.findall(hlo_text):
+        *dims, dtype = spec.split("x")
+        total += int(np.prod([int(d) for d in dims])) * _DTYPE_BYTES[dtype]
+    return total
+
+
+class TestWeightsAsOperand:
+    @pytest.fixture
+    def programs(self):
+        cfg = ARCHS["h2o-danube-3-4b"].reduced()
+        params = init_params(cfg, jax.random.PRNGKey(0), tp_size=1)
+        server = SessionServer(cfg, params, max_slots=1, max_len=32,
+                               scheduler="device")
+        slot = server.slots[0].value
+        prefill = jax.jit(server._prefill_kernel.fn).lower(
+            slot, server.weights.value, np.zeros((1, 8), np.int32))
+        cache = slot[0]
+        decode = jax.jit(server._decode_kernel.fn).lower(
+            server.weights.value,
+            (cache, np.zeros((1,), np.int32), np.int32(8)))
+        server.close()
+        return params, {"prefill": prefill, "decode": decode}
+
+    @pytest.mark.parametrize("kind", ["prefill", "decode"])
+    def test_weights_are_arguments_not_constants(self, programs, kind):
+        params, lowered = programs
+        lowered = lowered[kind]
+        leaves = jax.tree.leaves(params)
+        param_bytes = sum(leaf.nbytes for leaf in leaves)
+        arg_shapes = [tuple(a.shape) for a in jax.tree.leaves(
+            lowered.args_info)]
+        for leaf in leaves:
+            assert tuple(leaf.shape) in arg_shapes
+        assert _constant_bytes(lowered.as_text()) < param_bytes / 100
+
+    def test_non_finite_logits_yield_invalid_token(self):
+        from repro.runtime.serve import INVALID_TOKEN, _next_token
+
+        logits = np.zeros((2, 1, 8), np.float32)
+        logits[0, 0, 3] = 1.0
+        logits[1, 0, 5] = np.nan
+        toks = np.asarray(_next_token(logits, 8))
+        assert toks.tolist() == [3, INVALID_TOKEN]
+
+
+def test_mesh_serving_outputs_committed_to_shard_devices():
+    """Four forced host devices, one shard each: every slot's cache, token
+    and position live on the device of the shard that ran its chain, the
+    four slots land on four devices, each shard holds its own weight
+    replica, and the tokens equal a one-device server's."""
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    script = """
+import dataclasses
+import re
+import jax, numpy as np
+from repro.configs import ARCHS
+from repro.models import init_params
+from repro.runtime import SessionServer
+
+assert len(jax.devices()) == 4, jax.devices()
+cfg = dataclasses.replace(ARCHS["h2o-danube-3-4b"].reduced(), n_layers=1)
+params = init_params(cfg, jax.random.PRNGKey(0), tp_size=1)
+rng = np.random.RandomState(0)
+prompts = [rng.randint(0, cfg.vocab, n).astype(np.int32) for n in (5, 7, 5, 7)]
+
+def serve(**kw):
+    server = SessionServer(cfg, params, max_slots=4, max_len=24, **kw)
+    reqs = [server.submit(p, max_new=3) for p in prompts]
+    server.run_until_drained()
+    return server, [r.generated for r in reqs]
+
+def devices_of(tree):
+    return {d for leaf in jax.tree.leaves(tree) for d in leaf.devices()}
+
+mesh, tokens = serve(scheduler="mesh", n_shards=4)
+_, ref = serve(scheduler="device")
+assert tokens == ref, (tokens, ref)
+session = mesh.session
+seen = set()
+for buf in mesh.slots:
+    shard = session.shard_of(buf)
+    dev = session.devices[shard]
+    assert devices_of(buf.value) == {dev}, (buf.name, shard,
+                                            devices_of(buf.value))
+    seen.add(dev)
+assert len(seen) == 4, seen
+for shard in session._shards:
+    replica = shard._replicas[id(mesh.weights)]
+    assert devices_of(replica) == {shard.device}
+assert session.session_stats()["transfer_mode"] == "d2d"
+mesh.close()
+print("MESH_SERVE_OK")
+"""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = os.path.join(repo, "src") + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    proc = subprocess.run([sys.executable, "-c", script], cwd=repo, env=env,
+                          capture_output=True, text=True, timeout=150)
+    assert proc.returncode == 0, (proc.stdout + proc.stderr)[-2000:]
+    assert "MESH_SERVE_OK" in proc.stdout
