@@ -1,0 +1,17 @@
+"""Self ms of ``acs.host_task`` per token in the window: the session's host
+path: reading a task's inputs and its jit dispatch. Read from the difference
+of the program's span table (``session_stats()["spans"]``) across the
+window; None where the program has no such span."""
+
+NAMES = ("acs.host_task",)
+
+
+def read(ctx):
+    if ctx.get("kind") != "serve" or not ctx.get("tokens_in_window"):
+        return None
+    s0, s1 = (c.get("spans", {}) for c in ctx["counters"])
+    if not any(n in s1 for n in NAMES):
+        return None
+    seconds = sum(s1[n]["self_s"] - s0.get(n, {}).get("self_s", 0.0)
+                  for n in NAMES if n in s1)
+    return 1e3 * seconds / ctx["tokens_in_window"]

@@ -1,0 +1,19 @@
+"""Self ms of ``acs.launch`` and ``acs.compile`` per step in the window: the
+epoch's launch: compaction, arena rows for new buffers, host-to-device row
+refreshes and the enqueue of the dispatch (``acs.compile`` where the
+dispatch is a new program's first). Read from the difference of the
+program's span table (``session_stats()["spans"]``) across the window; None
+where the program has no such span."""
+
+NAMES = ("acs.launch", "acs.compile")
+
+
+def read(ctx):
+    if ctx.get("kind") != "sim" or not ctx.get("steps_in_window"):
+        return None
+    s0, s1 = (c.get("spans", {}) for c in ctx["counters"])
+    if not any(n in s1 for n in NAMES):
+        return None
+    seconds = sum(s1[n]["self_s"] - s0.get(n, {}).get("self_s", 0.0)
+                  for n in NAMES if n in s1)
+    return 1e3 * seconds / ctx["steps_in_window"]

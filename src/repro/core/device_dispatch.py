@@ -64,6 +64,8 @@ from .executors import ExecStats, SerialExecutor, group_by_signature
 from .scheduler import PLAN_MODES, SchedulerReport
 from .scoreboard import dependency_arrays
 from .session import SchedulerSession
+from . import spans
+from .spans import span
 from .task import Task, operand_base, operand_shape
 from .window import SchedulingWindow
 
@@ -1056,7 +1058,6 @@ class DeviceWindowRunner:
         stats.dispatches = 1  # the whole stream was one launch
         stats.tasks_run = len(tasks)
         stats.wave_widths = [len(w) for w in plan]
-        stats.exec_seconds = exec_time
         report = SchedulerReport(
             window, stats, plan_time + exec_time,
             [[t.tid for t in w] for w in plan],
@@ -1133,7 +1134,6 @@ class DeviceWindowRunner:
         stats.dispatches = 1
         stats.tasks_run = len(tasks)
         stats.wave_widths = [len(tasks)]
-        stats.exec_seconds = exec_time
         report = SchedulerReport(
             window, stats, plan_time + exec_time,
             [[t.tid for t in tasks]],
@@ -1216,7 +1216,6 @@ class DeviceWindowRunner:
         stats.dispatches = 1
         stats.tasks_run = len(tasks)
         stats.wave_widths = [len(w) for w in plan]
-        stats.exec_seconds = exec_time
         report = SchedulerReport(window, stats, plan_time + exec_time,
                                  [[t.tid for t in w] for w in plan])
         report.plan_seconds = plan_time  # type: ignore[attr-defined]
@@ -1468,14 +1467,17 @@ class DeviceSession(SchedulerSession):
         """Write the given buffers' slab rows back to host values (ONE
         blocking sync, counted; ``tags`` attributes it to the stream tags
         that forced it)."""
-        bufs = [b for b in buffers if id(b) in self._device_dirty]
-        if not bufs or self._slabs is None:
-            return
-        jax.block_until_ready(self._slabs)
-        self.arena.unpack(self._slabs, only=bufs)
-        for b in bufs:
-            del self._device_dirty[id(b)]
-        self._count_sync("d2h", tuple(tags))
+        with span("acs.sync"):
+            bufs = [b for b in buffers if id(b) in self._device_dirty]
+            if not bufs or self._slabs is None:
+                return
+            with span("acs.sync_wait"):
+                jax.block_until_ready(self._slabs)
+            with span("acs.unpack"):
+                self.arena.unpack(self._slabs, only=bufs)
+            for b in bufs:
+                del self._device_dirty[id(b)]
+            self._count_sync("d2h", tuple(tags))
 
     def sync(self) -> None:
         """Force every device-resident value back to host buffers."""
@@ -1632,9 +1634,28 @@ class DeviceSession(SchedulerSession):
         )
 
     def _execute_device(self, dev_plan: List[List[Task]]) -> None:
-        self._maybe_compact()
         tasks = [t for step in dev_plan for t in step]
-        self.arena.add_tasks(tasks)
+        with span("acs.launch"):
+            self._maybe_compact()
+            self.arena.add_tasks(tasks)
+        with span("acs.lower"):
+            cached, built = self._lower_steps(dev_plan)
+        run_fn, tables = cached[:2]
+
+        with span("acs.compile" if built else "acs.launch"):
+            # Persistent slabs: append rows for newly seen buffers, refresh
+            # rows whose host values changed since they were packed.
+            self._refresh_slabs(tasks)
+            out = run_fn(tuple(self._slabs), tables)
+            self._slabs = list(out)
+            self._note_dispatched(tasks)
+        for step in dev_plan:
+            self.stats.wave_widths.append(len(step))
+
+    def _lower_steps(self, dev_plan: List[List[Task]]) -> Tuple[Tuple, bool]:
+        """The plan-cache entry of a fixed-table dispatch (lowered on a
+        miss), and whether its program was built just now."""
+        built = False
         key = (self.plan_mode, self._structure_key(dev_plan))
         cached = self._plan_cache.get(key)
         if cached is not None and any(
@@ -1656,6 +1677,7 @@ class DeviceSession(SchedulerSession):
                 prog = _build_program(steps)
                 self._programs[spec_key] = prog
                 self.stats.compiles += 1
+                built = True
             run_fn, runs = prog
             tables = _run_tables(steps, runs)
             class_ids = sorted({
@@ -1674,19 +1696,14 @@ class DeviceSession(SchedulerSession):
             # LRU touch: reinsertion moves the entry to the young end.
             self._plan_cache[key] = self._plan_cache.pop(key)
             self.plan_cache_hits += 1
-        run_fn, tables, n_steps, _ = cached
+        return cached, built
 
-        # Persistent slabs: append rows for newly seen buffers, refresh
-        # rows whose host values changed since they were packed.
-        self._refresh_slabs(tasks)
-
-        out = run_fn(tuple(self._slabs), tables)
-        self._slabs = list(out)
+    def _note_dispatched(self, tasks: List[Task]) -> None:
+        """Count one device dispatch of ``tasks``; their outputs are now
+        newest in the slabs."""
         self.device_dispatches += 1
         self.stats.dispatches += 1
         self.stats.tasks_run += len(tasks)
-        for step in dev_plan:
-            self.stats.wave_widths.append(len(step))
         for t in tasks:
             for op in t.outputs:
                 b = operand_base(op)
@@ -1732,14 +1749,16 @@ class DeviceSession(SchedulerSession):
         if need:
             self._sync_to_host(need.values(), tags=self._tags_of(tasks))
         for task in tasks:
-            self._host_exec.run_task(task, self._host_inputs(task))
-            self.host_task_dispatches += 1
-            for op in task.outputs:
-                b = operand_base(op)
-                self._host_dirty[id(b)] = b
-                self._device_dirty.pop(id(b), None)
-            self.waves.append([task.tid])
-            self._note_retired(task)
+            with span("acs.host_task"):
+                self._host_exec.run_task(task, self._host_inputs(task))
+                self.host_task_dispatches += 1
+                for op in task.outputs:
+                    b = operand_base(op)
+                    self._host_dirty[id(b)] = b
+                    self._device_dirty.pop(id(b), None)
+                self.waves.append([task.tid])
+            with span("acs.retire"):
+                self._note_retired(task)
 
     def _host_inputs(self, task: Task) -> Tuple[Any, ...]:
         """A host-path task's input values, committed to the pinned device
@@ -1779,8 +1798,25 @@ class DeviceSession(SchedulerSession):
         same structure-keyed plan cache as the fixed-table path (payload
         arrays are cached device-side, so a recurring stream re-uploads
         nothing) and the same spec-keyed program cache."""
-        self._maybe_compact()
-        self.arena.add_tasks(tasks)
+        with span("acs.launch"):
+            self._maybe_compact()
+            self.arena.add_tasks(tasks)
+        with span("acs.lower"):
+            cached, built = self._lower_loop(tasks)
+        run_fn, payload = cached[:2]
+
+        with span("acs.compile" if built else "acs.launch"):
+            self._refresh_slabs(tasks)
+            out, _done = run_fn(tuple(self._slabs), payload)
+            self._slabs = list(out)
+            self._note_dispatched(tasks)
+        self.loop_dispatches += 1
+        self.stats.wave_widths.append(len(tasks))
+
+    def _lower_loop(self, tasks: List[Task]) -> Tuple[Tuple, bool]:
+        """The plan-cache entry of a ready-queue dispatch (lowered on a
+        miss), and whether its program was built just now."""
+        built = False
         key = ("loop", self._structure_key([tasks]))
         cached = self._plan_cache.get(key)
         if cached is not None and any(
@@ -1824,6 +1860,7 @@ class DeviceSession(SchedulerSession):
                     prog = _build_loop_interpreter(program.specs, program.fns)
                 self._programs[spec_key] = prog
                 self.stats.compiles += 1
+                built = True
             class_ids = sorted({
                 sp.class_id for st in program.specs
                 for sp in st.inputs + st.outputs})
@@ -1840,21 +1877,7 @@ class DeviceSession(SchedulerSession):
         else:
             self._plan_cache[key] = self._plan_cache.pop(key)
             self.plan_cache_hits += 1
-        run_fn, payload = cached[:2]
-
-        self._refresh_slabs(tasks)
-        out, _done = run_fn(tuple(self._slabs), payload)
-        self._slabs = list(out)
-        self.device_dispatches += 1
-        self.loop_dispatches += 1
-        self.stats.dispatches += 1
-        self.stats.tasks_run += len(tasks)
-        self.stats.wave_widths.append(len(tasks))
-        for t in tasks:
-            for op in t.outputs:
-                b = operand_base(op)
-                self._device_dirty[id(b)] = b
-                self._host_dirty.pop(id(b), None)
+        return cached, built
 
     def _run_epoch_loop(self) -> None:
         """The plan_mode="loop" epoch: split the program-order drain into
@@ -1862,7 +1885,8 @@ class DeviceSession(SchedulerSession):
         ready-queue dispatch (order decided on device); opaque-operand
         runs interleave on the host path in between. Program order is
         topological, so run ordering preserves every cross-run edge."""
-        order = self._drain_epoch_ordered()
+        with span("acs.plan"):
+            order = self._drain_epoch_ordered()
         syncs_before = self.host_syncs
         hits_before = self.plan_cache_hits
         n_device_dispatches = 0
@@ -1897,11 +1921,15 @@ class DeviceSession(SchedulerSession):
             progressed = self._drain_inflight(block=True) > 0
         if self.window.idle():
             return progressed
-        if self.plan_mode == "loop":
-            self._run_epoch_loop()
-        else:
-            self._run_epoch()
+        self._epoch()
         return True
+
+    def _epoch(self) -> None:
+        with span("acs.epoch"):
+            if self.plan_mode == "loop":
+                self._run_epoch_loop()
+            else:
+                self._run_epoch()
 
     # -- overlapped drain (mesh pump) ---------------------------------------
     def launch(self) -> bool:
@@ -1919,10 +1947,7 @@ class DeviceSession(SchedulerSession):
                 return bool(self._inflight)
             self._defer_retire = True
             try:
-                if self.plan_mode == "loop":
-                    self._run_epoch_loop()
-                else:
-                    self._run_epoch()
+                self._epoch()
             finally:
                 self._defer_retire = False
             return True
@@ -1968,20 +1993,22 @@ class DeviceSession(SchedulerSession):
         if self._defer_retire:
             self._inflight.append((dev_plan, tuple(self._slabs or ())))
             return
-        watched = bool(self._listeners) or any(
-            t.tid in self._watchers or t.tid in self._tickets
-            for step in dev_plan for t in step)
-        if watched:
-            self._sync_to_host(
-                list(self._device_dirty.values()),
-                tags=self._tags_of(t for step in dev_plan for t in step))
-        for step in dev_plan:
-            self.waves.append([t.tid for t in step])
-            for t in step:
-                self._note_retired(t)
+        with span("acs.retire"):
+            watched = bool(self._listeners) or any(
+                t.tid in self._watchers or t.tid in self._tickets
+                for step in dev_plan for t in step)
+            if watched:
+                self._sync_to_host(
+                    list(self._device_dirty.values()),
+                    tags=self._tags_of(t for step in dev_plan for t in step))
+            for step in dev_plan:
+                self.waves.append([t.tid for t in step])
+                for t in step:
+                    self._note_retired(t)
 
     def _run_epoch(self) -> None:
-        plan = self._plan_epoch()
+        with span("acs.plan"):
+            plan = self._plan_epoch()
         syncs_before = self.host_syncs
         hits_before = self.plan_cache_hits
         n_device_dispatches = 0
@@ -2063,11 +2090,12 @@ class DeviceSession(SchedulerSession):
                 # dependency-engine accounting (probe vs pairwise-equiv)
                 "dep_checks": self.window.stats.dep_checks,
                 "scoreboard_probes": self.window.stats.scoreboard_probes,
+                # named host phases, process-wide (core/spans.py)
+                "spans": spans.snapshot(),
             }
 
     def _finalize(self) -> SchedulerReport:
         wall = time.perf_counter() - self._t0
-        self.stats.exec_seconds = wall
         report = SchedulerReport(self.window, self.stats, wall, self.waves)
         report.plan_mode = self.plan_mode  # type: ignore[attr-defined]
         report.session_stats = self.session_stats()  # type: ignore[attr-defined]

@@ -54,7 +54,6 @@ class ExecStats:
         self.compiles = 0
         self.tasks_run = 0
         self.wave_widths: List[int] = []
-        self.exec_seconds = 0.0
         # Host-blocking device syncs (block_until_ready). Wave/serial
         # executors sync implicitly via value consumption; the frontier
         # path counts every explicit block so "syncs << dispatches" is a
@@ -70,7 +69,6 @@ class ExecStats:
             "waves": len(self.wave_widths),
             "mean_wave_width": float(w.mean()),
             "max_wave_width": int(w.max()),
-            "exec_seconds": self.exec_seconds,
             "blocking_syncs": self.blocking_syncs,
         }
 
@@ -90,7 +88,6 @@ class SerialExecutor:
         """Dispatch one task on the given input values (its buffers'
         values, or copies of them committed to the device it must run on)
         and write its outputs."""
-        t0 = time.perf_counter()
         fn = self._jit_cache.get(task.signature)
         if fn is None:
             fn = jax.jit(task.fn)
@@ -100,7 +97,6 @@ class SerialExecutor:
         self.stats.dispatches += 1
         self.stats.tasks_run += 1
         self.stats.wave_widths.append(1)
-        self.stats.exec_seconds += time.perf_counter() - t0
 
     def finalize(self) -> None:
         jax.block_until_ready(jax.numpy.zeros(()))
@@ -154,7 +150,6 @@ class FusedWaveExecutor:
     def execute_wave(self, tasks: Sequence[Task]) -> None:
         if not tasks:
             return
-        t0 = time.perf_counter()
         groups = _group_by_signature(tasks)
         key = self._wave_key(groups)
         wave_fn = self._wave_cache.get(key)
@@ -190,7 +185,6 @@ class FusedWaveExecutor:
                         t.write_outputs(outs[i])
             else:
                 g[0].write_outputs(outs)
-        self.stats.exec_seconds += time.perf_counter() - t0
 
     def finalize(self) -> None:
         jax.block_until_ready(jax.numpy.zeros(()))

@@ -246,10 +246,6 @@ class FrontierSession(SchedulerSession):
         ex = self.executor
         ex.finalize()
         wall = time.perf_counter() - self._t0
-        # Accumulate like every other executor: the executor (and its
-        # ExecStats) persists across sessions, so overwriting would pair
-        # last-run seconds with all-runs dispatch counters in deltas.
-        ex.stats.exec_seconds += wall
         return SchedulerReport(self.window, ex.stats, wall, self.waves,
                                groups=self.groups)
 

@@ -89,6 +89,7 @@ from __future__ import annotations
 import time
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
+from . import spans
 from .arena import ShardTransferTable
 from .buffers import Buffer
 from .device_dispatch import DeviceOpRegistry, DeviceSession
@@ -602,6 +603,9 @@ class MeshDeviceSession(SchedulerSession):
         honest breakdown (host_syncs per shard = the transfer audit)."""
         with self._lock:
             per_shard = [sh.session_stats() for sh in self._shards]
+            # The span table is process-wide: reported once, at the top.
+            for entry in per_shard:
+                del entry["spans"]
 
             def total(key: str) -> int:
                 return sum(s[key] for s in per_shard)
@@ -638,6 +642,7 @@ class MeshDeviceSession(SchedulerSession):
                 "dep_checks": self.window.stats.dep_checks,
                 "scoreboard_probes": self.window.stats.scoreboard_probes,
                 "per_shard": per_shard,
+                "spans": spans.snapshot(),
             }
 
     def _finalize(self) -> SchedulerReport:
@@ -652,7 +657,6 @@ class MeshDeviceSession(SchedulerSession):
             stats.tasks_run += sh.stats.tasks_run
             stats.compiles += sh.stats.compiles
             stats.wave_widths.extend(sh.stats.wave_widths)
-        stats.exec_seconds = wall
         report = SchedulerReport(self.window, stats, wall, self.waves)
         report.plan_mode = "mesh"  # type: ignore[attr-defined]
         report.session_stats = self.session_stats()  # type: ignore[attr-defined]

@@ -466,5 +466,4 @@ class ThreadedSession(SchedulerSession):
         if not self.window.drained():
             raise RuntimeError("threaded scheduler exited before draining the window")
         wall = time.perf_counter() - self._t0
-        self.stats.exec_seconds = wall
         return SchedulerReport(self.window, self.stats, wall, self.waves)

@@ -65,6 +65,7 @@ import numpy as np
 
 from ..core import BufferPool, TaskStream, WaveScheduler
 from ..core.executors import SerialExecutor
+from ..core.spans import span
 from ..core.wrapper import AcsKernel
 from ..models import decode_step, init_cache, prefill
 from ..models.config import ArchConfig
@@ -685,7 +686,9 @@ class SessionServer(_ServingCore):
         self.task_kinds.pop(task.tid, None)
         req = self.active[slot]
         _, tok, _ = self.slots[slot].value
-        req.generated.append(int(np.asarray(tok)[0]))
+        with span("serve.token_read", rid=req.rid):
+            tok = int(np.asarray(tok)[0])
+        req.generated.append(tok)
         req.rounds_left -= 1
         if not boundary:
             return
@@ -760,7 +763,8 @@ class SessionServer(_ServingCore):
             req = self._pick_next()
             if req is None:  # everything queued is quota-blocked/held back
                 break
-            self._admit(req)
+            with span("serve.admit", rid=req.rid):
+                self._admit(req)
 
     def _admit(self, req: Request) -> None:
         """Emit the request's kernel program into the live window at

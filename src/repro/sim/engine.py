@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.buffers import Buffer, BufferPool
+from ..core.spans import span
 from ..core.task import Task
 from ..core.wrapper import AcsKernel, TaskStream
 from .envs import EnvSpec, initial_state
@@ -235,73 +236,75 @@ class PhysicsEngine:
 
     # -- broadphase (host side; the source of input-dependence) ------------
     def _active_pairs(self, group: _Group) -> List[int]:
-        pos = np.asarray(group.state.value)[..., :3]  # [g, B, 3]
-        thresh = 2.0 * self.spec.radius + self.margin
-        act = []
-        for ci, (a, b) in enumerate(self.candidates):
-            d = np.linalg.norm(pos[:, b] - pos[:, a], axis=-1)
-            if np.any(d < thresh):
-                act.append(ci)
-        return act
+        with span("sim.broadphase"):
+            pos = np.asarray(group.state.value)[..., :3]  # [g, B, 3]
+            thresh = 2.0 * self.spec.radius + self.margin
+            act = []
+            for ci, (a, b) in enumerate(self.candidates):
+                d = np.linalg.norm(pos[:, b] - pos[:, a], axis=-1)
+                if np.any(d < thresh):
+                    act.append(ci)
+            return act
 
     # -- emission -----------------------------------------------------------
     def emit_step(self, stream: TaskStream, policy: Optional[Callable] = None) -> None:
         """Launch one simulation step's kernels for every group, exactly as
         an application would: per-group, program order, single stream."""
-        spec, g = self.spec, self.group_size
-        for gi, grp in enumerate(self.groups):
-            # fresh ctrl buffer per (group, step): host-produced actions
-            if policy is not None:
-                actions = np.asarray(policy(np.asarray(grp.obs.value)), np.float32)
-            else:
-                actions = self.rng.uniform(-1, 1, size=(g, spec.n_joints)).astype(np.float32)
-            ctrl = self.pool.alloc(
-                (g, spec.n_joints), np.float32,
-                f"ctrl{gi}_s{self._step_index}", jnp.asarray(actions),
-            )
-
-            for j, (p, c) in enumerate(spec.joints):
-                # reads full state + this joint's control column;
-                # writes its OWN jf row -> joints are mutually independent.
-                _JOINT.launch(
-                    stream,
-                    inputs=(grp.state, ctrl),
-                    outputs=(grp.jf.row_view(j, 1),),
-                    static_args=(j, p, c, 0.35, _KP, _KD),
+        with span("sim.emit"):
+            spec, g = self.spec, self.group_size
+            for gi, grp in enumerate(self.groups):
+                # fresh ctrl buffer per (group, step): host-produced actions
+                if policy is not None:
+                    actions = np.asarray(policy(np.asarray(grp.obs.value)), np.float32)
+                else:
+                    actions = self.rng.uniform(-1, 1, size=(g, spec.n_joints)).astype(np.float32)
+                ctrl = self.pool.alloc(
+                    (g, spec.n_joints), np.float32,
+                    f"ctrl{gi}_s{self._step_index}", jnp.asarray(actions),
                 )
 
-            active = self._active_pairs(grp)
-            self.stats.active_contacts.append(len(active))
-            for ci in active:
-                a, b = self.candidates[ci]
-                _CONTACT.launch(
-                    stream,
-                    inputs=(grp.state,),
-                    outputs=(grp.cf.row_view(ci, 1),),
-                    static_args=(a, b, spec.radius, _KC),
+                for j, (p, c) in enumerate(spec.joints):
+                    # reads full state + this joint's control column;
+                    # writes its OWN jf row -> joints are mutually independent.
+                    _JOINT.launch(
+                        stream,
+                        inputs=(grp.state, ctrl),
+                        outputs=(grp.jf.row_view(j, 1),),
+                        static_args=(j, p, c, 0.35, _KP, _KD),
+                    )
+
+                active = self._active_pairs(grp)
+                self.stats.active_contacts.append(len(active))
+                for ci in active:
+                    a, b = self.candidates[ci]
+                    _CONTACT.launch(
+                        stream,
+                        inputs=(grp.state,),
+                        outputs=(grp.cf.row_view(ci, 1),),
+                        static_args=(a, b, spec.radius, _KC),
+                    )
+
+                _GROUND.launch(
+                    stream, inputs=(grp.state,), outputs=(grp.gf,),
+                    static_args=(spec.radius, _KG),
                 )
 
-            _GROUND.launch(
-                stream, inputs=(grp.state,), outputs=(grp.gf,),
-                static_args=(spec.radius, _KG),
-            )
+                parents = tuple(p for p, _ in spec.joints)
+                children = tuple(c for _, c in spec.joints)
+                pa = tuple(self.candidates[ci][0] for ci in active)
+                pb = tuple(self.candidates[ci][1] for ci in active)
+                _INTEGRATE.launch(
+                    stream,
+                    inputs=(grp.state, grp.jf, grp.gf) + tuple(grp.cf.row_view(ci, 1) for ci in active),
+                    outputs=(grp.state,),
+                    static_args=(parents, children, pa, pb, len(active), spec.mass, self.dt),
+                )
+                _OBSERVE.launch(stream, inputs=(grp.state,), outputs=(grp.obs,))
 
-            parents = tuple(p for p, _ in spec.joints)
-            children = tuple(c for _, c in spec.joints)
-            pa = tuple(self.candidates[ci][0] for ci in active)
-            pb = tuple(self.candidates[ci][1] for ci in active)
-            _INTEGRATE.launch(
-                stream,
-                inputs=(grp.state, grp.jf, grp.gf) + tuple(grp.cf.row_view(ci, 1) for ci in active),
-                outputs=(grp.state,),
-                static_args=(parents, children, pa, pb, len(active), spec.mass, self.dt),
-            )
-            _OBSERVE.launch(stream, inputs=(grp.state,), outputs=(grp.obs,))
-
-        self.stats.kernels = len(stream.tasks)
-        self.stats.steps += 1
-        self.stats.candidate_contacts = len(self.candidates)
-        self._step_index += 1
+            self.stats.kernels = len(stream.tasks)
+            self.stats.steps += 1
+            self.stats.candidate_contacts = len(self.candidates)
+            self._step_index += 1
 
     def emit_batch(self, stream: TaskStream, n_steps: int,
                    policy: Optional[Callable] = None) -> None:
