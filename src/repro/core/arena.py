@@ -46,6 +46,10 @@ workloads:
   **generation** counter bumps — the signal consumers holding static row
   addresses (the `DeviceSession` plan cache) use to invalidate exactly
   the affected entries.
+* The **read-back** moves whole classes, not rows: ``unpack`` transfers
+  each touched class to the host once (``unpack_transfers``) and cuts
+  rows and padding in NumPy; ``unpack_on_device`` leaves device slices
+  for a caller that hands the values straight to a jit call.
 """
 
 from __future__ import annotations
@@ -53,6 +57,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -72,11 +77,15 @@ def _commit_like(val: Any, slab: Any) -> Any:
     raises jax's incompatible-devices error. Uncommitted slabs (single
     device, plain host sessions) pass through untouched."""
     if getattr(slab, "committed", False):
-        import jax
-
         (dev,) = slab.devices()
         return jax.device_put(val, dev)
     return val
+
+
+@jax.jit
+def _take_rows(slab: Any, idx: Any) -> Any:
+    """The rows ``idx`` of ``slab``, as one device op."""
+    return slab[idx]
 
 
 def row_capacity(n_rows: int) -> int:
@@ -221,6 +230,7 @@ class SlabArena:
         self.recycled_rows = 0
         self.compactions = 0
         self.unpack_rows_written = 0
+        self.unpack_transfers = 0
 
     # -- classification ----------------------------------------------------
     def class_of(self, buf: Buffer) -> ShapeClass:
@@ -524,15 +534,13 @@ class SlabArena:
     @staticmethod
     def _place(val: Any, device: Optional[Any]) -> Any:
         """Commit a row value onto ``device`` before it is stacked with
-        sibling rows. Host values are not guaranteed co-located: after a
-        cross-shard unpack, ``buf.value`` is a slice of the OWNING shard's
-        slab, committed to that shard's device — stacking two such rows
-        from different shards raises jax's incompatible-devices error
-        unless the consumer pins them onto its own device first."""
+        sibling rows. Host values are not guaranteed co-located: after
+        another shard's :meth:`unpack_on_device` or host-path task,
+        ``buf.value`` is committed to THAT shard's device — stacking two
+        such rows from different shards raises jax's incompatible-devices
+        error unless the consumer pins them onto its own device first."""
         if device is None:
             return val
-        import jax
-
         return jax.device_put(val, device)
 
     def _row_value(self, buf: Optional[Buffer], cls: ShapeClass):
@@ -641,35 +649,73 @@ class SlabArena:
                 _commit_like(val.astype(out[cid].dtype), out[cid]))
         return out
 
-    def unpack(self, slabs: Sequence[Any],
-               only: Optional[Iterable[Buffer]] = None) -> None:
-        """Write slab rows back into buffer values, slicing padding off.
-
-        ``only`` restricts writeback to the given buffers (e.g. the ones
-        some task actually wrote) and resolves each through the address map
-        — O(|only|), not O(total resident rows); default writes every live
-        resident row. Buffers already released are skipped: their rows may
-        have been recycled and no host value is owed.
-        """
+    def _touched(self, only: Optional[Iterable[Buffer]]
+                 ) -> Dict[int, List[Tuple[int, Buffer]]]:
+        """``class_id -> [(row, buffer)]`` of the rows to read back.
+        ``only`` resolves through the address map — O(|only|), not
+        O(total resident rows); default is every live resident row.
+        Buffers already released are skipped: their rows may have been
+        recycled and no host value is owed."""
+        touched: Dict[int, List[Tuple[int, Buffer]]] = {}
         if only is not None:
             for buf in only:
                 addr = self._addr.get(id(buf))
-                if addr is None:
-                    continue
-                cid, row = addr
-                self._write_back(buf, slabs[cid], row, self._classes[cid])
-            return
-        for cid, cls in enumerate(self._classes):
-            slab = slabs[cid]
-            for row, buf in enumerate(self._rows[cid]):
-                if buf is None:
-                    continue
-                self._write_back(buf, slab, row, cls)
+                if addr is not None:
+                    touched.setdefault(addr[0], []).append((addr[1], buf))
+            return touched
+        for cid, rows in enumerate(self._rows):
+            live = [(row, buf) for row, buf in enumerate(rows)
+                    if buf is not None]
+            if live:
+                touched[cid] = live
+        return touched
 
-    def _write_back(self, buf: Buffer, slab: Any, row: int,
-                    cls: ShapeClass) -> None:
-        val = slab[row]
-        if tuple(buf.shape) != cls.padded_shape:
-            val = val[tuple(slice(0, s) for s in buf.shape)]
-        buf.value = val
-        self.unpack_rows_written += 1
+    def unpack(self, slabs: Sequence[Any],
+               only: Optional[Iterable[Buffer]] = None) -> None:
+        """Read slab rows back into host values: each buffer's value
+        becomes a NumPy array of its true shape, padding sliced off.
+
+        ``only`` restricts the read-back to the given buffers (e.g. the
+        ones some task actually wrote); see :meth:`_touched`. Each touched
+        class costs one device op and one device-to-host transfer (counted
+        in ``unpack_transfers``), all started together: the whole slab
+        when the touched rows fill at least half of it, else one gather of
+        the touched rows, its index padded to :func:`row_capacity` so the
+        gather compiles O(log rows) times per slab shape. Rows and padding
+        are then cut on the host, each into its own copy, so that a kept
+        value does not pin the whole host slab.
+        """
+        touched = self._touched(only)
+        picks, dense = [], []
+        for cid, items in touched.items():
+            slab = slabs[cid]
+            dense.append(2 * len(items) >= slab.shape[0])
+            if dense[-1]:
+                picks.append(slab)
+                continue
+            idx = np.zeros(row_capacity(len(items)), np.int32)
+            idx[:len(items)] = [row for row, _ in items]
+            picks.append(_take_rows(slab, idx))
+        hosts = jax.device_get(picks)
+        for items, whole, host in zip(touched.values(), dense, hosts):
+            for i, (row, buf) in enumerate(items):
+                cut = (row if whole else i,) + tuple(
+                    slice(0, s) for s in buf.shape)
+                buf.value = np.array(host[cut])  # a copy, never a view
+                self.unpack_rows_written += 1
+        self.unpack_transfers += len(touched)
+
+    def unpack_on_device(self, slabs: Sequence[Any],
+                         buffers: Iterable[Buffer]) -> None:
+        """Point the given buffers' values at their slab rows, padding
+        sliced off, without leaving the device: the read-back of a
+        host-path task that feeds the values to a jit call on the same
+        device. Resolves ``buffers`` as :meth:`unpack` resolves ``only``."""
+        for cid, items in self._touched(buffers).items():
+            cls = self._classes[cid]
+            for row, buf in items:
+                val = slabs[cid][row]
+                if tuple(buf.shape) != cls.padded_shape:
+                    val = val[tuple(slice(0, s) for s in buf.shape)]
+                buf.value = val
+                self.unpack_rows_written += 1

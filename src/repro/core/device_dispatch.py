@@ -1463,10 +1463,13 @@ class DeviceSession(SchedulerSession):
             self.host_syncs_by_tag[tag] = self.host_syncs_by_tag.get(tag, 0) + 1
 
     def _sync_to_host(self, buffers: Iterable[Buffer],
-                      tags: Iterable[str] = ()) -> None:
+                      tags: Iterable[str] = (), *,
+                      on_device: bool = False) -> None:
         """Write the given buffers' slab rows back to host values (ONE
         blocking sync, counted; ``tags`` attributes it to the stream tags
-        that forced it)."""
+        that forced it). The values are NumPy arrays, one transfer per
+        touched class; ``on_device`` keeps them as device slices instead,
+        for the in-epoch host path that hands them to a jit call."""
         with span("acs.sync"):
             bufs = [b for b in buffers if id(b) in self._device_dirty]
             if not bufs or self._slabs is None:
@@ -1474,7 +1477,10 @@ class DeviceSession(SchedulerSession):
             with span("acs.sync_wait"):
                 jax.block_until_ready(self._slabs)
             with span("acs.unpack"):
-                self.arena.unpack(self._slabs, only=bufs)
+                if on_device:
+                    self.arena.unpack_on_device(self._slabs, bufs)
+                else:
+                    self.arena.unpack(self._slabs, only=bufs)
             for b in bufs:
                 del self._device_dirty[id(b)]
             self._count_sync("d2h", tuple(tags))
@@ -1747,7 +1753,8 @@ class DeviceSession(SchedulerSession):
                 if id(base) in self._device_dirty:
                     need[id(base)] = base
         if need:
-            self._sync_to_host(need.values(), tags=self._tags_of(tasks))
+            self._sync_to_host(need.values(), tags=self._tags_of(tasks),
+                               on_device=True)
         for task in tasks:
             with span("acs.host_task"):
                 self._host_exec.run_task(task, self._host_inputs(task))
@@ -2075,6 +2082,7 @@ class DeviceSession(SchedulerSession):
                 "host_syncs_d2h": self.host_syncs_d2h,
                 "host_syncs_h2d": self.host_syncs_h2d,
                 "host_syncs_by_tag": dict(self.host_syncs_by_tag),
+                "unpack_transfers": self.arena.unpack_transfers,
                 "d2d_row_exports": self.d2d_row_exports,
                 "d2d_row_imports": self.d2d_row_imports,
                 "row_invalidations": self.row_invalidations,

@@ -504,6 +504,98 @@ class TestRowLifecycle:
                 np.asarray(b.value), np.full(5, expected[id(b)], np.float32))
 
 
+# case -> (buffer shapes, dtype, indices passed as ``only`` (None: every
+# live row), indices released before the read-back, gathers expected).
+# Slab capacity is row_capacity(rows): a class whose touched rows fill at
+# least half of it is read whole, any other through one gather.
+UNPACK_CASES = {
+    "f32-padded-dense": ([(5,)] * 3 + [(7,)] * 2 + [(3, 6)] * 4,
+                         np.float32, None, (), 0),
+    "f32-unpadded-sparse": ([(8,)] * 2 + [(3, 8)], np.float32, None, (), 2),
+    "bf16-padded-dense": ([(2, 6)] * 4 + [(2, 5)], jnp.bfloat16, None, (), 0),
+    "i32-dense-and-sparse": ([(3, 6)] * 5 + [(16,)], np.int32, None, (), 1),
+    "f32-scalars": ([()] * 4, np.float32, None, (), 0),
+    "only-sparse": ([(5,)] * 12, np.float32, [0, 7, 11], (), 1),
+    "only-dense": ([(5,)] * 12, np.float32, list(range(8)), (), 0),
+    "dead-row": ([(5,)] * 6, np.float32, None, (2,), 0),
+    "released-in-only": ([(5,)] * 6 + [(3, 6)], np.float32, [1, 2, 3, 6],
+                         (2,), 2),
+    "only-released": ([(5,)] * 3, np.float32, [1], (1,), 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(UNPACK_CASES))
+def test_batched_unpack_matches_per_row_slicing(case, monkeypatch):
+    """``unpack`` reads each touched class back in one transfer and cuts
+    rows and padding on the host: every value equals the per-row device
+    slice bit for bit, as its own NumPy array; released and untouched
+    buffers keep their values; and the host values go back to the device
+    unchanged through ``update_rows`` and ``pack_incremental``."""
+    import repro.core.arena as arena_mod
+
+    shapes, dtype, only, freed, gathers = UNPACK_CASES[case]
+    rng = np.random.RandomState(0)
+    pool, arena = BufferPool(), SlabArena(pad_multiple=8)
+    bufs = [pool.alloc(s, dtype, value=jnp.asarray(
+                rng.randn(*s) * 8, np.float32).astype(dtype))
+            for s in shapes]
+    for b in bufs:
+        arena.add(b)
+    # Device rows differ from the host values, padding included: the
+    # read-back must come from the slabs.
+    slabs = [s + jnp.ones_like(s) for s in arena.pack()]
+    for i in freed:
+        arena.free(bufs[i])
+    picked = range(len(bufs)) if only is None else only
+    live = [i for i in picked if i not in freed]
+    want = {}
+    for i in live:
+        cid, row = arena.addr_of(bufs[i])
+        cut = tuple(slice(0, s) for s in bufs[i].shape)
+        want[i] = np.asarray(slabs[cid][row][cut]).astype(np.float32)
+    before = [b.value for b in bufs]
+    taken = []
+    take = arena_mod._take_rows
+
+    def spy(slab, idx):
+        taken.append(len(idx))
+        return take(slab, idx)
+
+    monkeypatch.setattr(arena_mod, "_take_rows", spy)
+    arena.unpack(slabs, only=None if only is None else [bufs[i] for i in only])
+
+    for i, b in enumerate(bufs):
+        if i not in want:
+            assert b.value is before[i]
+            continue
+        assert isinstance(b.value, np.ndarray) and b.value.flags.owndata
+        assert b.value.dtype == np.dtype(dtype)
+        assert b.value.shape == tuple(b.shape)
+        np.testing.assert_array_equal(b.value.astype(np.float32), want[i])
+    assert arena.unpack_rows_written == len(live)
+    assert arena.unpack_transfers == len(
+        {arena.addr_of(bufs[i])[0] for i in live})
+    assert len(taken) == gathers
+    assert all(n == row_capacity(n) for n in taken)
+
+    mine = [bufs[i] for i in live]
+    if not mine:
+        return
+    again = SlabArena(pad_multiple=8)
+    again.add(mine[0])
+    fresh = again.pack()
+    for b in mine[1:]:
+        again.add(b)
+    again.unpack(again.pack_incremental(fresh))
+    slabs = arena.update_rows(slabs, mine)
+    for b in mine:
+        b.value = None
+    arena.unpack(slabs, only=mine)
+    for i in live:
+        np.testing.assert_array_equal(bufs[i].value.astype(np.float32),
+                                      want[i])
+
+
 class TestCrossDevicePinnedSlabs:
     """A mesh shard pins its session's slabs to its own device, but the
     buffers fed to it may hold arrays committed to ANOTHER device — a

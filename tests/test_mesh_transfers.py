@@ -200,6 +200,40 @@ class TestReplicatedBuffers:
             session.flush()
 
 
+class TestStagedReadBack:
+    def test_staged_edge_equals_one_session(self):
+        """A host-staged edge reads the owner's row back as a NumPy value
+        (the host read-back); the destination uploads it again, and the
+        result equals one DeviceSession's over the same stream."""
+        from repro.core import DeviceSession
+
+        pool = BufferPool()
+        bufs, tasks = _cross_shard_stream(pool)
+        sess = MeshDeviceSession(window_size=32, n_shards=N_SHARDS,
+                                 transfer_mode="staged")
+        staged = []
+        for sh in sess._shards:
+            def spy(bufs_arg, _orig=sh.sync_buffers, _sh=sh, **kw):
+                bufs_arg = list(bufs_arg)
+                dirty = [b for b in bufs_arg if id(b) in _sh._device_dirty]
+                _orig(bufs_arg, **kw)
+                staged.extend(b.value for b in dirty)
+
+            sh.sync_buffers = spy
+        sess.submit(tasks)
+        sess.close()
+        assert sess.session_stats()["staged_moves"] > 0
+        assert staged and all(isinstance(v, np.ndarray) for v in staged)
+
+        pool = BufferPool()
+        one_bufs, one_tasks = _cross_shard_stream(pool)
+        one = DeviceSession(window_size=32, plan_mode="loop")
+        one.submit(one_tasks)
+        one.close()
+        np.testing.assert_array_equal(_snap(bufs), _snap(one_bufs))
+        np.testing.assert_array_equal(_snap(bufs), _serial_ref())
+
+
 class TestLateObserverSync:
     """Satellite: a late observer of a retired task must sync only the
     shards owning that task's operands — not sweep every shard."""

@@ -335,6 +335,83 @@ class TestDeviceSessionObservation:
         assert report.window_stats["retired"] == 30
 
 
+class TestDeviceSessionReadBack:
+    """Host read-backs (flush, sync) hand out NumPy values, one transfer
+    per touched class; the in-epoch host path keeps its inputs on the
+    device."""
+
+    @pytest.mark.parametrize("plan_mode", ["wave", "loop"])
+    def test_flush_of_a_sim_gives_host_values_equal_to_serial(self,
+                                                              plan_mode):
+        from repro.core import DeviceSession
+        from repro.sim import ENVIRONMENTS, PhysicsEngine
+
+        ref, dev = (PhysicsEngine(ENVIRONMENTS["cheetah"], n_envs=4,
+                                  group_size=2, seed=0) for _ in range(2))
+        s = DeviceSession(plan_mode=plan_mode)
+        for step in range(3):
+            stream = TaskStream()
+            ref.emit_step(stream)
+            run_serial(stream.tasks)
+            stream = TaskStream()
+            dev.emit_step(stream)
+            s.submit(stream.tasks)
+            before = s.session_stats()
+            s.flush()
+            after = s.session_stats()
+            assert after["host_syncs_d2h"] - before["host_syncs_d2h"] == 1
+            moved = after["unpack_transfers"] - before["unpack_transfers"]
+            assert 0 < moved <= after["n_classes"]
+            for g in dev.groups:
+                assert isinstance(g.state.value, np.ndarray)
+            np.testing.assert_array_equal(dev.state_snapshot(),
+                                          ref.state_snapshot())
+        s.close()
+
+    def test_host_path_task_reads_device_resident_inputs(self):
+        """A device step, then a host-path task (an opaque operand) that
+        reads its output in the same epoch: the input is read back as a
+        device slice, so the task gets a jax.Array and no upload follows."""
+        import jax
+
+        from repro.core import DeviceSession
+
+        pool = BufferPool()
+        x = pool.alloc((D,), np.float32, value=jnp.ones(D))
+        y = pool.alloc((D,), np.float32, value=jnp.zeros(D))
+        z = pool.alloc((D,), np.float32, value=jnp.zeros(D))
+        scale = pool.alloc((1,), np.float32, value=(jnp.full(D, 2.0),))
+        tasks = []
+        for fn, ins, outs in ((_axpy, (x, x), (y,)),
+                              (lambda v, p: v * p[0], (y, scale), (z,))):
+            r, w = default_segments(ins, outs)
+            tasks.append(Task(opcode=f"op{len(tasks)}", fn=fn, inputs=ins,
+                              outputs=outs, read_segments=r,
+                              write_segments=w))
+        s = DeviceSession(window_size=4)
+        seen = []
+        run_task = s._host_exec.run_task
+
+        def spy(task, values):
+            seen.append(values)
+            return run_task(task, values)
+
+        s._host_exec.run_task = spy
+        s.submit(tasks)
+        s.poll()
+        stats = s.session_stats()
+        assert stats["device_dispatches"] == 1
+        assert stats["host_task_dispatches"] == 1
+        assert stats["host_syncs_d2h"] == 1 and stats["host_syncs_h2d"] == 0
+        assert stats["unpack_transfers"] == 0
+        (values,) = seen
+        assert isinstance(values[0], jax.Array)
+        assert isinstance(y.value, jax.Array)
+        s.close()
+        np.testing.assert_array_equal(np.asarray(z.value),
+                                      np.full(D, 7.0, np.float32))
+
+
 class TestBufferPoolFree:
     def test_free_releases_name_without_recycling_addresses(self):
         pool = BufferPool()
